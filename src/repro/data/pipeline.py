@@ -11,6 +11,7 @@ so the global stream is independent of the mesh layout).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -37,16 +38,20 @@ class SyntheticLMDataset:
         # deterministic state->token expansion
         self._emit = rng.integers(0, self.vocab_size, size=(s, 4))
 
+    @partial(jax.profiler.annotate_function, name="pipeline.batch")
     def batch(self, step: int) -> dict:
+        """Host spans: ``pipeline.batch`` around the call,
+        ``pipeline.markov`` around the per-position sampling loop."""
         rng = np.random.default_rng((self.seed, step))
         b, t, s = self.batch_size, self.seq_len, self.order_states
         states = np.zeros((b, t + 1), np.int64)
         states[:, 0] = rng.integers(0, s, size=b)
         u = rng.random((b, t))
         cdf = np.cumsum(self._trans, axis=-1)
-        for i in range(t):
-            states[:, i + 1] = np.argmax(cdf[states[:, i]] > u[:, i:i + 1],
-                                         axis=-1)
+        with jax.profiler.TraceAnnotation("pipeline.markov"):
+            for i in range(t):
+                states[:, i + 1] = np.argmax(
+                    cdf[states[:, i]] > u[:, i:i + 1], axis=-1)
         emit_choice = rng.integers(0, self._emit.shape[1], size=(b, t + 1))
         tokens = self._emit[states, emit_choice].astype(np.int32)
         return {

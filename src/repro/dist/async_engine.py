@@ -262,6 +262,7 @@ def make_async_train_step(cfg: ArchConfig, opt, mesh, acfg: AsyncConfig,
             flat_specs = treedef.flatten_up_to(pspecs)
         return flat_g, treedef
 
+    @jax.named_scope("compress")
     def _compress_dense(grads, err):
         flat_g, treedef = _leaf_specs(grads)
         flat_e = treedef.flatten_up_to(err)
@@ -326,13 +327,15 @@ def make_async_train_step(cfg: ArchConfig, opt, mesh, acfg: AsyncConfig,
                 geoms = [leaf_rows_geometry(p.shape, sp)
                          for p, sp in zip(flat_p, flat_specs)]
             prior_rows, accs = [], []
-            for acc in treedef.flatten_up_to(local["acc"]):
-                prior_rows.append(acc[step % cap])
-                accs.append(acc.at[step % cap].set(0.0))
+            with jax.named_scope("deliver"):
+                for acc in treedef.flatten_up_to(local["acc"]):
+                    prior_rows.append(acc[step % cap])
+                    accs.append(acc.at[step % cap].set(0.0))
         else:
             # densified rings, double-buffered take: consume earlier
             # steps' deliveries before the fresh deposit lands
-            prior, buf = DLV.tree_ring_take(local["buf"], step % cap)
+            with jax.named_scope("deliver"):
+                prior, buf = DLV.tree_ring_take(local["buf"], step % cap)
 
         # ---- compute half -------------------------------------------------
         # jax 0.4.x partial-auto shard_map: unroll model scans (scan_utils)
@@ -368,18 +371,21 @@ def make_async_train_step(cfg: ArchConfig, opt, mesh, acfg: AsyncConfig,
             for g, e, sp, geom, acc, prior in zip(
                     flat_g, flat_e, flat_specs, geoms, accs, prior_rows):
                 # local sparsification to the compact wire form
-                payload, new_err = ef_compress_leaf_compact(
-                    g, e, sp, acfg.compressor, acfg.topk_ratio,
-                    impl=acfg.kernel_impl)
+                with jax.named_scope("compress"):
+                    payload, new_err = ef_compress_leaf_compact(
+                        g, e, sp, acfg.compressor, acfg.topk_ratio,
+                        impl=acfg.kernel_impl)
                 new_errs.append(new_err)
                 # ---- launch-reduce half: the wire is this all-gather of
                 # the compact payload; ONE fused scatter deposits every
                 # live message into its slot — tau == 0 self-deliveries
                 # land in the just-zeroed slot t and are taken right back
-                gathered = {key: _gather(v) for key, v in payload.items()}
-                acc = _deposit(acc, gathered, w_live, slots)
-                delivered.append(prior + acc[step % cap])
-                new_accs.append(acc.at[step % cap].set(0.0))
+                with jax.named_scope("deliver"):
+                    gathered = {key: _gather(v)
+                                for key, v in payload.items()}
+                    acc = _deposit(acc, gathered, w_live, slots)
+                    delivered.append(prior + acc[step % cap])
+                    new_accs.append(acc.at[step % cap].set(0.0))
             local["acc"] = jax.tree.unflatten(treedef, new_accs)
             if acfg.has_err:
                 local["err"] = jax.tree.unflatten(treedef, new_errs)
@@ -404,10 +410,11 @@ def make_async_train_step(cfg: ArchConfig, opt, mesh, acfg: AsyncConfig,
             # fresh payload lands tau steps ahead; the own-step (tau == 0)
             # remainder joins the pre-consumed deliveries — bitwise the
             # single-take slot content, one full-width pmean either way
-            buf = DLV.tree_ring_deposit(
-                buf, (step + d_eff) % cap,
-                jax.tree.map(lambda v: v * alive, payload))
-            own, buf = DLV.tree_ring_take(buf, step % cap)
+            with jax.named_scope("deliver"):
+                buf = DLV.tree_ring_deposit(
+                    buf, (step + d_eff) % cap,
+                    jax.tree.map(lambda v: v * alive, payload))
+                own, buf = DLV.tree_ring_take(buf, step % cap)
             local["buf"] = buf
             stale = jax.tree.map(lambda a, b: a + b, prior, own)
 

@@ -56,9 +56,10 @@ def loss_fn(cfg: ArchConfig, params, batch: dict,
     batch stays uniformly shardable over the data axes.
     """
     logits, aux = TF.forward(cfg, params, batch, flags)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)
-    ce = jnp.mean(nll)
+    with jax.named_scope("lm_head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)
+        ce = jnp.mean(nll)
     loss = ce + cfg.router_aux_weight * aux
     if "loss_scale" in batch:
         loss = loss * jnp.mean(batch["loss_scale"].astype(jnp.float32))
@@ -76,6 +77,7 @@ def _microbatch(batch, n: int):
         lambda a: a.reshape(n, a.shape[0] // n, *a.shape[1:]), batch)
 
 
+@jax.named_scope("model_fwd_bwd")
 def mean_grads(cfg, flags, params, batch, grad_accum: int):
     """Loss + mean gradient, optionally accumulated over ``grad_accum``
     microbatches with a ``lax.scan`` (keeps the HLO one-microbatch sized).
@@ -116,6 +118,7 @@ def tree_all_finite(tree) -> jax.Array:
     return out
 
 
+@jax.named_scope("optimizer")
 def guarded_update(opt, grads, opt_state, params, *, skip_nonfinite: bool):
     """Optimizer update with an optional skip-step guard: when
     ``skip_nonfinite`` and any gradient leaf is NaN/Inf, params and

@@ -10,6 +10,16 @@ pipeline, optimizer, checkpointing and any gradient-sync strategy:
 so the data-parallel sync strategies are exercised with real cross-shard
 traffic; without it the mesh spans every device JAX finds.
 
+``--profile-dir DIR --profile-steps A:B`` writes a profiler trace of steps
+A..B-1 under DIR, and the compiled step's HLO text as ``DIR/step.hlo.txt``.
+Each step is a ``train_step`` step annotation holding the host spans
+``batch.device_put``, ``step.call``, ``loss.fetch`` and ``ckpt.save``
+(the data pipeline adds ``pipeline.batch``); the step's device ops carry
+the named scopes of ``repro.dist``. Reduce the trace with
+``python3 bench/trace_reduce.py <trace> train_step`` (busy, idle gaps) and
+``python3 -m bench.scopes DIR`` (device time per scope). A profiled run
+compiles its programs afresh rather than from the persistent cache.
+
 :func:`train` is the trainer itself: it takes parsed arguments and an
 ``ArchConfig``, so a caller in the same process (``chip_smoke.py``) can
 drive a depth-cut configuration through the same code as the CLI.
@@ -64,7 +74,58 @@ def parse_args(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    return ap.parse_args(argv)
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of --profile-steps here")
+    ap.add_argument("--profile-steps", type=profile_steps, default=None,
+                    metavar="A:B", help="trace steps A..B-1")
+    args = ap.parse_args(argv)
+    if bool(args.profile_dir) != (args.profile_steps is not None):
+        ap.error("--profile-dir and --profile-steps go together")
+    return args
+
+
+def profile_steps(text: str) -> tuple:
+    """``"A:B"`` -> ``(A, B)``, the steps A..B-1 to trace."""
+    a, sep, b = text.partition(":")
+    if not (sep and a.isdigit() and b.isdigit() and int(a) < int(b)):
+        raise argparse.ArgumentTypeError(
+            f"want A:B with 0 <= A < B, got {text!r}")
+    return int(a), int(b)
+
+
+@dataclass
+class StepProfile:
+    """Traces the loop's steps ``first..stop-1`` into ``directory`` with
+    the benchmark's profiler options (host spans on, Python tracer off),
+    then writes the compiled step's HLO text beside the trace so device
+    ops can be mapped to their named scopes."""
+
+    directory: str
+    first: int
+    stop: int
+    on: bool = False
+
+    def before(self, t: int) -> None:
+        import jax
+        if not self.on and self.first <= t < self.stop:
+            opts = jax.profiler.ProfileOptions()
+            opts.host_tracer_level = 2
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.directory, profiler_options=opts)
+            self.on = True
+
+    def after(self, t: int, compiled) -> None:
+        if self.on and t + 1 >= self.stop:
+            self.finish(compiled)
+
+    def finish(self, compiled) -> None:
+        import jax
+        if not self.on:
+            return
+        jax.profiler.stop_trace()
+        self.on = False
+        with open(os.path.join(self.directory, "step.hlo.txt"), "w") as f:
+            f.write(compiled.as_text())
 
 
 def force_host_devices(n: int) -> None:
@@ -93,8 +154,20 @@ def main(argv=None):
     force_host_devices(args.devices)
     from repro.configs import get_config
     from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
+    if args.profile_dir:
+        fresh_compiles()
+    else:
+        enable_compile_cache()
     return train(args, get_config(args.arch)).losses
+
+
+def fresh_compiles() -> None:
+    """Compile every program of this process afresh. The persistent
+    cache's key leaves out the ops' metadata, so a cached step carries
+    the ``op_name`` scopes of whatever code first compiled it, which a
+    profile is read through."""
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
 
 
 def train(args, cfg) -> TrainRun:
@@ -240,47 +313,61 @@ def train(args, cfg) -> TrainRun:
             # and a benign scale of 1.0 is bitwise-neutral
             batch = dict(batch, loss_scale=np.full(
                 (args.batch,), injector.loss_scale(t), np.float32))
-        return jax.device_put(batch, SH.named(
-            mesh, SH.batch_specs(cfg, mesh, batch)))
+        with jax.profiler.TraceAnnotation("batch.device_put"):
+            return jax.device_put(batch, SH.named(
+                mesh, SH.batch_specs(cfg, mesh, batch)))
 
     result = TrainRun(losses=[])
     losses = result.losses
     skipped = 0
+    profile = (StepProfile(args.profile_dir, *args.profile_steps)
+               if args.profile_dir else None)
     for t in range(step_idx, args.steps):
-        batch = batch_at(t)
-        if result.compiled is None:
+        if profile:
+            profile.before(t)
+        with jax.profiler.StepTraceAnnotation("train_step", step_num=t):
+            batch = batch_at(t)
+            if result.compiled is None:
+                t0 = time.perf_counter()
+                result.compiled = jstep.lower(params, opt_state, sync_state,
+                                              batch).compile()
+                result.compile_s = time.perf_counter() - t0
             t0 = time.perf_counter()
-            result.compiled = jstep.lower(params, opt_state, sync_state,
-                                          batch).compile()
-            result.compile_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        params, opt_state, sync_state, metrics = result.compiled(
-            params, opt_state, sync_state, batch)
-        losses.append(float(metrics["loss"]))
-        result.step_s.append(time.perf_counter() - t0)
-        skipped += int(float(metrics.get("nonfinite", 0.0)) > 0)
-        if t % args.log_every == 0:
-            gap = float(metrics.get("gap2_over_alpha2",
-                                    metrics.get("stale_gap2", 0.0)))
-            tau = ""
-            if "mean_tau" in metrics:
-                tau = f"  tau {float(metrics['mean_tau']):.2f}"
-            print(f"step {t:5d}  loss {losses[-1]:.4f}  gap2/a2 {gap:.4g}"
-                  f"{tau}", flush=True)
-        if args.ckpt_dir and args.ckpt_every and \
-                (t + 1) % args.ckpt_every == 0:
-            try:
-                if injector is not None:
-                    injector.check_ckpt_io(t + 1)
-                save_checkpoint(args.ckpt_dir, t + 1,
-                                (params, opt_state, sync_state))
-            except OSError as e:
-                # checkpointing is best-effort: warn and keep training —
-                # the next save (or the torn-ckpt skip in latest_step)
-                # covers recovery
-                print(f"ckpt save failed at step {t + 1}: {e}", flush=True)
-        if injector is not None:
-            injector.maybe_kill(t)
+            with jax.profiler.TraceAnnotation("step.call"):
+                params, opt_state, sync_state, metrics = result.compiled(
+                    params, opt_state, sync_state, batch)
+            with jax.profiler.TraceAnnotation("loss.fetch"):
+                losses.append(float(metrics["loss"]))
+                result.step_s.append(time.perf_counter() - t0)
+                skipped += int(float(metrics.get("nonfinite", 0.0)) > 0)
+            if t % args.log_every == 0:
+                gap = float(metrics.get("gap2_over_alpha2",
+                                        metrics.get("stale_gap2", 0.0)))
+                tau = ""
+                if "mean_tau" in metrics:
+                    tau = f"  tau {float(metrics['mean_tau']):.2f}"
+                print(f"step {t:5d}  loss {losses[-1]:.4f}  gap2/a2 "
+                      f"{gap:.4g}{tau}", flush=True)
+            if args.ckpt_dir and args.ckpt_every and \
+                    (t + 1) % args.ckpt_every == 0:
+                try:
+                    if injector is not None:
+                        injector.check_ckpt_io(t + 1)
+                    with jax.profiler.TraceAnnotation("ckpt.save"):
+                        save_checkpoint(args.ckpt_dir, t + 1,
+                                        (params, opt_state, sync_state))
+                except OSError as e:
+                    # checkpointing is best-effort: warn and keep training
+                    # — the next save (or the torn-ckpt skip in
+                    # latest_step) covers recovery
+                    print(f"ckpt save failed at step {t + 1}: {e}",
+                          flush=True)
+            if injector is not None:
+                injector.maybe_kill(t)
+        if profile:
+            profile.after(t, result.compiled)
+    if profile:
+        profile.finish(result.compiled)
     if injector is not None:
         print(f"faults: poisoned={injector.poisoned_steps} "
               f"skipped={skipped} ckpt_errors={injector.ckpt_errors}",

@@ -126,7 +126,8 @@ def rwkv6_block(params, cfg, x, *, state=None):
     log_w = -jnp.exp(w_raw).reshape(b, t, h, n)        # < 0
 
     wkv0 = None if state is None else state["wkv"]
-    o, new_wkv = wkv6_chunked(r, k, v, log_w, params["bonus_u"], wkv0)
+    with jax.named_scope("wkv"):
+        o, new_wkv = wkv6_chunked(r, k, v, log_w, params["bonus_u"], wkv0)
     o = rmsnorm(o.reshape(b, t, d), params["ln_x"], cfg.norm_eps) * g
     tm_out = o @ params["w_o"].astype(dt_)
 

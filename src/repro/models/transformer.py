@@ -132,6 +132,7 @@ def embed_input(cfg: ArchConfig, params, batch: dict,
     return x
 
 
+@jax.named_scope("lm_head")
 def lm_logits(cfg: ArchConfig, params, x: jax.Array) -> jax.Array:
     from repro.models.actx import constrain
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
