@@ -13,7 +13,8 @@ VMEM, a primitive Mosaic does not lower).
     None, the jnp reference otherwise.  Never the interpreter.
 
 Every resolution made while tracing is counted per call site
-(:func:`resolutions`), so a run can report what each site resolved to.
+(:func:`resolutions`), so a run can report what each site resolved to; a
+site that picks between jnp paths counts its pick with :func:`record`.
 """
 from __future__ import annotations
 
@@ -72,8 +73,13 @@ def choose(site: str, impl: str, why_not: str | None = None):
             how = f"ref (backend {backend()})"
         else:
             how = f"ref ({why_not})"
-    _RESOLVED[site][how] += 1
+    record(site, how)
     return use, interpret
+
+
+def record(site: str, how: str) -> None:
+    """Count one resolution ``how`` at ``site``."""
+    _RESOLVED[site][how] += 1
 
 
 def resolutions() -> dict:

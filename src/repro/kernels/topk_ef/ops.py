@@ -3,18 +3,28 @@ production sync strategy (rows = model-sharded dims, cols compressed).
 
 The kernel takes (8, R) row blocks whole in VMEM, so it needs M a multiple
 of 8 and an R whose block fits :data:`repro.kernels.dispatch.VMEM_BUDGET`;
-any other call resolves by the rule in `repro.kernels.dispatch`."""
+any other call resolves by the rule in `repro.kernels.dispatch`.  Where
+the kernel does not take the call, the selection is exact either way and
+picked by the row length alone: ``lax.top_k`` (a sort) for short rows,
+the threshold search of `select.py` from :data:`THRESHOLD_MIN_R` on.  The
+pick is counted at the ``topk_ef.select`` site as ``sort`` or
+``threshold``."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch import choose, vmem_why_not
+from repro.kernels.dispatch import choose, record, vmem_why_not
 from repro.kernels.topk_ef.kernel import topk_ef, vmem_bytes
 from repro.kernels.topk_ef.ref import topk_ef_ref
+from repro.kernels.topk_ef.select import topk_threshold_ef
 
 SITE = "topk_ef"
+SELECT_SITE = "topk_ef.select"
 BLOCK_ROWS = 8
+#: Row length from which the threshold search is faster than the sort of
+#: ``lax.top_k`` on a v5e chip (k = R / 16; PERF.md section 6).
+THRESHOLD_MIN_R = 1 << 15
 
 
 def why_not(m: int, r: int, k: int) -> str | None:
@@ -34,6 +44,10 @@ def compress_leaf(g2d: jax.Array, err2d: jax.Array, ratio: float, *,
     if use_kernel:
         return topk_ef(g2d, err2d, k=k, block_rows=BLOCK_ROWS,
                        interpret=interpret)
+    if r >= THRESHOLD_MIN_R:
+        record(SELECT_SITE, "threshold")
+        return topk_threshold_ef(g2d, err2d, k=k)
+    record(SELECT_SITE, "sort")
     return topk_ef_ref(g2d, err2d, k=k)
 
 

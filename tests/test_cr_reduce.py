@@ -257,3 +257,72 @@ if HAVE_HYPOTHESIS:
                              for p_ in payloads], axis=0)
         np.testing.assert_allclose(np.asarray(fused), dense,
                                    atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# selection by row length: threshold search for long rows, sort below
+# ---------------------------------------------------------------------------
+
+def test_compress_leaf_selects_by_row_length():
+    """compress_leaf counts its pick at ``topk_ef.select`` by the row
+    length alone — the threshold search from THRESHOLD_MIN_R on, the sort
+    of lax.top_k below — and the async engine's compact path, whose long
+    leaves take the threshold search, walks the trajectory of the
+    densified path, which keeps lax.top_k."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.data.pipeline import SyntheticLMDataset
+    from repro.dist import sharding as SH
+    from repro.dist.async_engine import (AsyncConfig, init_async_state,
+                                         make_async_train_step)
+    from repro.jax_compat import make_mesh
+    from repro.kernels import dispatch
+    from repro.kernels.topk_ef import ops as TK
+    from repro.models import transformer as TF
+    from repro.models.params import init_params, param_specs
+    from repro.optim import momentum
+
+    for r, how in [(TK.THRESHOLD_MIN_R, "threshold"),
+                   (TK.THRESHOLD_MIN_R - 1, "sort")]:
+        dispatch.reset_resolutions()
+        row = jax.ShapeDtypeStruct((1, r), jnp.float32)
+        jax.eval_shape(lambda a: TK.compress_leaf(a, a, 1 / 16), row)
+        assert dispatch.resolutions()[TK.SELECT_SITE] == {how: 1}
+
+    base = get_config("qwen3-1.7b").reduced()
+    cfg = dataclasses.replace(
+        base, d_ff=max(base.d_ff, TK.THRESHOLD_MIN_R // base.d_model))
+    mesh = make_mesh((1, 1), ("data", "model"))
+    flags = TF.RunFlags(remat=False)
+    defs = TF.model_defs(cfg)
+    pspecs = param_specs(defs, SH.axis_sizes(mesh))
+    params = init_params(defs, jax.random.PRNGKey(0))
+    opt = momentum(1e-2, 0.9)
+    data = SyntheticLMDataset(cfg.vocab_size, 32, 4, seed=0)
+    batches = [data.batch(t) for t in range(4)]
+
+    def run(overlap):
+        acfg = AsyncConfig(tau_max=1, schedule="uniform", seed=1,
+                           compressor="topk", topk_ratio=1 / 16,
+                           overlap=overlap)
+        state = init_async_state(acfg, mesh, params,
+                                 pspecs if acfg.fused else None)
+        dispatch.reset_resolutions()
+        step = jax.jit(make_async_train_step(cfg, opt, mesh, acfg, pspecs,
+                                             flags))
+        p, opt_state, traj = params, opt.init(params), []
+        for b in batches:
+            p, opt_state, state, m = step(p, opt_state, state, b)
+            traj.append((float(m["loss"]),
+                         [np.asarray(x) for x in jax.tree.leaves(p)]))
+        return traj, dispatch.resolutions().get(TK.SELECT_SITE, {})
+
+    fused, picks = run(True)
+    assert picks.get("threshold", 0) > 0 and picks.get("sort", 0) > 0
+    legacy, _ = run(False)
+    for t, ((lf, pf), (ll, pl)) in enumerate(zip(fused, legacy)):
+        assert lf == ll, f"loss diverged at step {t}"
+        for a, b in zip(pf, pl):
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=0,
+                                       err_msg=f"step {t}")

@@ -8,7 +8,8 @@ import pytest
 from repro.kernels.onebit_ef import onebit_ef, onebit_ef_ref, unpack
 from repro.kernels.swa_attention import swa_decode_attention, swa_decode_ref
 from repro.kernels.topk_ef import topk_ef, topk_ef_ref
-from repro.kernels.topk_ef.ops import decompress_sum
+from repro.kernels.topk_ef.ops import THRESHOLD_MIN_R, decompress_sum
+from repro.kernels.topk_ef import select
 
 
 @pytest.mark.parametrize("m,r,k", [(8, 64, 4), (16, 256, 8), (32, 128, 1),
@@ -25,6 +26,49 @@ def test_topk_ef_matches_ref(m, r, k, dtype):
     np.testing.assert_allclose(np.sort(np.abs(v1), 1), np.sort(np.abs(v2), 1),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(e1, e2, rtol=1e-5, atol=1e-5)
+
+
+def _select_rows(kind, m, r, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "gauss":
+        return rng.standard_normal((m, r)).astype(np.float32)
+    if kind == "int_ties":      # ~1/7 of the row per value: ties cross k
+        return rng.integers(-3, 4, (m, r)).astype(np.float32)
+    if kind == "zeros":         # every magnitude ties, one of them -0.0
+        w = np.zeros((m, r), np.float32)
+        w[:, r // 3] = -0.0
+        return w
+    # denormals: magnitudes below the smallest normal float32
+    return (rng.standard_normal((m, r)) * 1e-39).astype(np.float32)
+
+
+@pytest.mark.parametrize("m,r,k", [
+    (1, THRESHOLD_MIN_R - 1, (THRESHOLD_MIN_R - 1) // 16),  # below
+    (8, THRESHOLD_MIN_R + 5, (THRESHOLD_MIN_R + 5) // 16),  # above
+    (8, 96, 1),
+    (1, 200, 200),
+])
+@pytest.mark.parametrize("kind", ["gauss", "int_ties", "zeros", "denormal"])
+@pytest.mark.parametrize("residual", [False, True])
+def test_threshold_select_matches_ref(kind, m, r, k, residual):
+    """The threshold search picks lax.top_k's set, ties to the lowest
+    index, and leaves a bit-identical residual; idx come ascending.  The
+    row is split between ``g`` and an EF residual ``err`` or all in
+    ``g``."""
+    w = _select_rows(kind, m, r, m * r + k)
+    half = np.float32(0.5) * w if residual else np.zeros_like(w)
+    g, e = jnp.asarray(w - half), jnp.asarray(half)
+    v1, i1, e1 = jax.jit(select.topk_threshold_ef,
+                         static_argnames="k")(g, e, k=k)
+    v2, i2, e2 = jax.jit(topk_ef_ref, static_argnames="k")(g, e, k=k)
+    order = np.argsort(np.asarray(i2), axis=1)
+    np.testing.assert_array_equal(
+        np.asarray(i1), np.take_along_axis(np.asarray(i2), order, 1))
+    np.testing.assert_array_equal(
+        np.asarray(v1).view(np.int32),
+        np.take_along_axis(np.asarray(v2), order, 1).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(e1).view(np.int32),
+                                  np.asarray(e2).view(np.int32))
 
 
 @pytest.mark.parametrize("m,r", [(8, 64), (16, 256), (8, 1024), (24, 96)])
