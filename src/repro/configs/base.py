@@ -40,11 +40,20 @@ class ArchConfig:
     sliding_window: int = 0      # 0 => full causal attention
     global_every: int = 0        # gemma3: every Nth layer is global (rest SWA)
     rope_theta: float = 1e4
+    # --- latent attention (MLA; kv_lora_rank 0 => plain GQA) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0           # the router's outputs (the published count)
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0        # experts 0..held-1 live here (0 => all)
     router_aux_weight: float = 0.01
+    router_score: str = "softmax"  # softmax | sigmoid (+ a choice-only bias)
+    router_scale: float = 1.0    # routed_scaling_factor
+    n_shared_experts: int = 0    # one gated MLP of n_shared * moe_d_ff
+    first_k_dense: int = 0       # leading layers with a dense MLP of d_ff
     moe_d_ff: int = 0            # expert hidden size (0 => d_ff)
     # --- SSM (mamba2 / rwkv6) ---
     ssm_state: int = 0
@@ -74,6 +83,14 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def expert_d_ff(self) -> int:
@@ -110,12 +127,15 @@ class ArchConfig:
         if not self.tie_embeddings:
             n += v * d
         hd = self.resolved_head_dim
-        for _ in range(self.n_layers):
+        for i in range(self.n_layers):
             if self.block_type == BLOCK_ATTN:
-                n += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd \
-                    + self.n_heads * hd * d  # qkvo
-                if self.is_moe:
-                    n += self.n_experts * 3 * d * self.expert_d_ff + d * self.n_experts
+                n += self._attn_params()
+                if self.is_moe and i >= self.first_k_dense:
+                    n += self.n_held * 3 * d * self.expert_d_ff \
+                        + d * self.n_experts \
+                        + 3 * d * self.n_shared_experts * self.expert_d_ff
+                    if self.router_score == "sigmoid":
+                        n += self.n_experts  # the choice bias
                 else:
                     n += 3 * d * self.d_ff
                 n += 2 * d  # norms
@@ -128,13 +148,26 @@ class ArchConfig:
             n += 4 * d * d + 3 * d * self.d_ff
         return n
 
+    def _attn_params(self) -> int:
+        d, h, hd = self.d_model, self.n_heads, self.resolved_head_dim
+        if not self.is_mla:
+            return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return (d * h * qk                                   # q
+                + d * (self.kv_lora_rank + self.qk_rope_head_dim)  # kv_a
+                + self.kv_lora_rank                          # kv_a norm
+                + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                           + self.v_head_dim)  # kv_b
+                + h * self.v_head_dim * d)                   # o
+
     def active_param_count(self) -> int:
         """Parameters touched per token (MoE: only routed experts)."""
         if not self.is_moe:
             return self.param_count()
         full = self.param_count()
         per_layer_expert = 3 * self.d_model * self.expert_d_ff
-        inactive = self.n_layers * (self.n_experts - self.experts_per_token) * per_layer_expert
+        inactive = (self.n_layers - self.first_k_dense) * \
+            (self.n_held - self.experts_per_token) * per_layer_expert
         return full - inactive
 
     def reduced(self) -> "ArchConfig":
@@ -142,6 +175,10 @@ class ArchConfig:
         d_model<=512, <=4 experts)."""
         d = min(self.d_model, 128)
         n_heads = min(self.n_heads, 4) if self.n_heads else 0
+        mla = {}
+        if self.is_mla:
+            mla = dict(kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
         n_kv = min(self.n_kv_heads, n_heads) if self.n_kv_heads else 0
         if self.block_type == BLOCK_RWKV6:
             ssm_state, ssm_heads = 16, d // 16  # rwkv requires h*n == d
@@ -161,12 +198,15 @@ class ArchConfig:
             head_dim=d // n_heads if n_heads else 0,
             n_experts=min(self.n_experts, 4) if self.is_moe else 0,
             experts_per_token=min(self.experts_per_token, 2) if self.is_moe else 0,
+            experts_held=0,
+            first_k_dense=min(self.first_k_dense, 1),
             ssm_state=ssm_state,
             ssm_heads=ssm_heads,
             sliding_window=min(self.sliding_window, 32) if self.sliding_window else 0,
             global_every=self.global_every,
             shared_attn_every=self.shared_attn_every,
             n_prefix_embeds=min(self.n_prefix_embeds, 8) if self.n_prefix_embeds else 0,
+            **mla,
         )
 
 

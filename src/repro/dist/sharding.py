@@ -239,10 +239,4 @@ def make_act_rules(cfg: ArchConfig, mesh, *, batch_size: int, seq_len: int,
         # (B, S, V)
         "logits": P(batch, None, model_if(cfg.vocab_size)),
     }
-    if cfg.is_moe:
-        e = model_if(cfg.n_experts)
-        # (E, G, C, d) / (E, G, C, ff): expert parallelism over model; the
-        # token-group dim follows the batch when it is globally sharded.
-        rules["moe_expert"] = P(e, batch, None, None)
-        rules["moe_hidden"] = P(e, batch, None, None)
     return {k: NamedSharding(mesh, s) for k, s in rules.items()}

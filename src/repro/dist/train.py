@@ -46,8 +46,9 @@ def loss_fn(cfg: ArchConfig, params, batch: dict,
             flags: TF.RunFlags = TF.DEFAULT_FLAGS):
     """Token-level cross entropy (+ weighted MoE router aux loss).
 
-    Returns ``(loss, metrics)`` where metrics carries the unweighted parts;
-    differentiable in ``params`` (use with ``value_and_grad(has_aux=True)``).
+    Returns ``(loss, metrics)`` where metrics carries the unweighted parts
+    and an MoE model's counters (`TF.forward`); differentiable in
+    ``params`` (use with ``value_and_grad(has_aux=True)``).
 
     An optional ``batch["loss_scale"]`` (shape (B,), normally all-ones)
     multiplies the loss — the fault-injection channel: a NaN/Inf scale
@@ -55,15 +56,15 @@ def loss_fn(cfg: ArchConfig, params, batch: dict,
     then reject (`repro.faults`).  Shaped (B,) rather than scalar so the
     batch stays uniformly shardable over the data axes.
     """
-    logits, aux = TF.forward(cfg, params, batch, flags)
+    logits, stats = TF.forward(cfg, params, batch, flags)
     with jax.named_scope("lm_head"):
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         nll = -jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)
         ce = jnp.mean(nll)
-    loss = ce + cfg.router_aux_weight * aux
+    loss = ce + cfg.router_aux_weight * stats["aux_loss"]
     if "loss_scale" in batch:
         loss = loss * jnp.mean(batch["loss_scale"].astype(jnp.float32))
-    return loss, {"ce": ce, "aux_loss": aux}
+    return loss, {"ce": ce, **stats}
 
 
 def _value_and_grad(cfg, flags):
@@ -89,7 +90,9 @@ def mean_grads(cfg, flags, params, batch, grad_accum: int):
 
     micro = _microbatch(batch, grad_accum)
     zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    init = (jnp.zeros(()), {"ce": jnp.zeros(()), "aux_loss": jnp.zeros(())},
+    parts_shape = jax.eval_shape(vg, params, jax.tree.map(lambda a: a[0],
+                                                          micro))[0][1]
+    init = (jnp.zeros(()), jax.tree.map(lambda _: jnp.zeros(()), parts_shape),
             zeros)
 
     def body(carry, mb):

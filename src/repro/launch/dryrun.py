@@ -371,6 +371,8 @@ def should_skip(cfg: ArchConfig, shape: InputShape):
     if shape.name == "long_500k" and not cfg.sub_quadratic:
         return ("long_500k requires sub-quadratic attention; "
                 f"{cfg.name} is full-attention (see DESIGN.md)")
+    if shape.kind != "train":
+        return TF.serving_refusal(cfg)
     return None
 
 

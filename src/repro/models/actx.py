@@ -13,8 +13,6 @@ Kinds:
   attn_q       (B, S, H, hd)    — projected q / attention output
   attn_kv      (B, S, K, hd)    — projected k/v
   logits       (B, S, V)        — LM head output, model on V
-  moe_expert   (E, G, C, d)     — dispatched expert inputs/outputs
-  moe_hidden   (E, G, C, ff)    — expert FFN hidden
 """
 from __future__ import annotations
 

@@ -26,6 +26,7 @@ from repro.configs.base import (ArchConfig, BLOCK_ATTN, BLOCK_MAMBA2,
                                 FRONTEND_VISION)
 from repro.models import layers as L
 from repro.models import mamba2 as M2
+from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.models import rwkv6 as R6
 from repro.models import scan_utils as SU
@@ -62,13 +63,13 @@ def _constrain(x, flags: RunFlags):
 # Parameter declaration
 # ---------------------------------------------------------------------------
 
-def _attn_layer_defs(cfg: ArchConfig) -> dict:
+def _attn_layer_defs(cfg: ArchConfig, moe: bool) -> dict:
     d = {
         "ln_attn": rmsnorm_def(cfg.d_model),
-        "attn": attention_defs(cfg),
+        "attn": MLA.mla_defs(cfg) if cfg.is_mla else attention_defs(cfg),
         "ln_mlp": rmsnorm_def(cfg.d_model),
     }
-    if cfg.is_moe:
+    if moe:
         d["moe"] = MOE.moe_defs(cfg)
     else:
         d["mlp"] = mlp_defs(cfg.d_model, cfg.d_ff)
@@ -89,15 +90,22 @@ def model_defs(cfg: ArchConfig) -> dict:
     if not cfg.tie_embeddings:
         defs["lm_head"] = ParamDef((d, v), ("embed", "vocab"))
 
+    n_layers = cfg.n_layers
     if cfg.block_type == BLOCK_ATTN:
-        layer = _attn_layer_defs(cfg)
+        layer = _attn_layer_defs(cfg, cfg.is_moe)
+        if cfg.first_k_dense:
+            # the leading dense layers: a stack of their own before the
+            # MoE stack (each stack scans one kind of layer)
+            n_layers -= cfg.first_k_dense
+            defs["dense_layers"] = stack_defs(_attn_layer_defs(cfg, False),
+                                              cfg.first_k_dense)
     elif cfg.block_type == BLOCK_MAMBA2:
         layer = _mamba_layer_defs(cfg)
     elif cfg.block_type == BLOCK_RWKV6:
         layer = R6.rwkv6_defs(cfg)
     else:
         raise ValueError(cfg.block_type)
-    defs["layers"] = stack_defs(layer, cfg.n_layers)
+    defs["layers"] = stack_defs(layer, n_layers)
 
     if cfg.shared_attn_every:
         defs["shared_attn"] = {
@@ -146,23 +154,63 @@ def lm_logits(cfg: ArchConfig, params, x: jax.Array) -> jax.Array:
 # Attention stacks
 # ---------------------------------------------------------------------------
 
+def serving_refusal(cfg: ArchConfig) -> Optional[str]:
+    """Why the serving paths (dense cache and paged) cannot run ``cfg``,
+    or None: latent attention has no latent cache here."""
+    if cfg.is_mla:
+        return (f"{cfg.name}: latent attention (MLA) has no serving cache "
+                "here (a latent paged cache is later work)")
+    return None
+
+
+def _check_servable(cfg: ArchConfig) -> None:
+    why = serving_refusal(cfg)
+    if why:
+        raise NotImplementedError(why)
+
+
+def _zero_stats(moe: bool) -> dict:
+    """The per-layer statistics a stack carries: the router's aux loss, and
+    for MoE layers the assignments to held experts and the largest
+    per-expert load."""
+    if moe:
+        return {"aux_loss": 0.0, "moe_assigned": 0.0, "moe_max_load": 0.0}
+    return {"aux_loss": 0.0}
+
+
+def _add_stats(acc: dict, new: dict) -> dict:
+    return {k: (jnp.maximum(v, new[k]) if k == "moe_max_load"
+                else v + new[k]) for k, v in acc.items()}
+
+
+def attn_half(cfg, flags, lp, x, positions, window, kv_cache=None,
+               cache_index=None):
+    """A block's attention and residual: (x, kv)."""
+    y = rmsnorm(x, lp["ln_attn"], cfg.norm_eps)
+    if cfg.is_mla:
+        h, kv = MLA.mla_block(lp["attn"], cfg, y, positions), None
+    else:
+        h, kv = attention_block(
+            lp["attn"], cfg, y, positions,
+            window=window, kv_cache=kv_cache, cache_index=cache_index)
+    return _constrain(x + h, flags), kv
+
+
 def _attn_block_body(cfg, flags, lp, x, positions, window,
                      kv_cache=None, cache_index=None, collect_kv=False):
-    """One transformer block. Returns (x, aux, kv)."""
-    h, kv = attention_block(
-        lp["attn"], cfg, rmsnorm(x, lp["ln_attn"], cfg.norm_eps), positions,
-        window=window, kv_cache=kv_cache, cache_index=cache_index)
-    x = _constrain(x + h, flags)
-    aux = jnp.zeros((), jnp.float32)
+    """One transformer block. Returns (x, stats, kv)."""
+    x, kv = attn_half(cfg, flags, lp, x, positions, window,
+                       kv_cache=kv_cache, cache_index=cache_index)
+    stats = {"aux_loss": jnp.zeros((), jnp.float32)}
     y = rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
-    if cfg.is_moe:
-        out, aux = MOE.moe_block(lp["moe"], cfg, y)
+    if "moe" in lp:
+        out, stats = MOE.moe_block(lp["moe"], cfg, y)
     else:
         out = mlp_block(lp["mlp"], y)
     x = _constrain(x + out, flags)
     if not collect_kv and kv_cache is None:
         kv = None
-    return x, aux, kv
+    return x, stats, kv
 
 
 def _maybe_remat(fn, flags: RunFlags):
@@ -195,7 +243,7 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
     """Run the full attention stack.
 
     stacked: layer params with leading L dim. kv_caches: None or
-    (k (L,B,T,K,D), v (L,B,T,K,D)). Returns (x, aux_sum, kv_out) where
+    (k (L,B,T,K,D), v (L,B,T,K,D)). Returns (x, stats, kv_out) where
     kv_out is stacked (L, ...) when collect_kv or decoding.
     """
     windows = cfg.layer_window_sizes()
@@ -204,6 +252,7 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
         # scan with trip count 0 so output structures (kv caches) survive.
         windows = [0]
     uniform = len(set(windows)) == 1
+    zero = _zero_stats("moe" in stacked)
 
     def body_for(window):
         def body(carry, scanned):
@@ -219,12 +268,12 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
                 collect_kv=collect_kv)
             if kv is None:
                 kv = ()
-            return (x, aux + a), kv
+            return (x, _add_stats(aux, a)), kv
         return _maybe_remat(body, flags)
 
     if uniform:
         xs = stacked if kv_caches is None else (stacked, kv_caches)
-        (x, aux), kvs = SU.scan(body_for(windows[0]), (x, 0.0), xs)
+        (x, aux), kvs = SU.scan(body_for(windows[0]), (x, zero), xs)
         return x, aux, kvs
 
     # grouped local:global pattern (gemma3): scan over groups of `g` layers
@@ -249,7 +298,7 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
             x, a, kv = _attn_block_body(
                 cfg, flags, lp, x, positions, w, kv_cache=cache,
                 cache_index=cache_index, collect_kv=collect_kv)
-            aux = aux + a
+            aux = _add_stats(aux, a)
             kvs.append(kv if kv is not None else ())
         if kvs and kvs[0] != ():
             kvs = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
@@ -260,7 +309,7 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
     head_params = reshape_groups(_tree_slice(stacked, 0, n_groups * g))
     xs = head_params if kv_caches is None else (
         head_params, reshape_groups(_tree_slice(kv_caches, 0, n_groups * g)))
-    (x, aux), kvs = SU.scan(_maybe_remat(group_body, flags), (x, 0.0), xs)
+    (x, aux), kvs = SU.scan(_maybe_remat(group_body, flags), (x, zero), xs)
     if kvs != ():
         kvs = jax.tree.map(
             lambda a: a.reshape(n_groups * g, *a.shape[2:]), kvs)
@@ -273,7 +322,7 @@ def attn_stack(cfg: ArchConfig, flags: RunFlags, stacked, x, positions,
         x, a, kv = _attn_block_body(
             cfg, flags, lp, x, positions, w, kv_cache=cache,
             cache_index=cache_index, collect_kv=collect_kv)
-        aux = aux + a
+        aux = _add_stats(aux, a)
         rem_kvs.append(kv if kv is not None else ())
     if rem_kvs and rem_kvs[0] != ():
         rem_kvs = jax.tree.map(lambda *xs: jnp.stack(xs), *rem_kvs)
@@ -429,20 +478,30 @@ def ssm_stack(cfg: ArchConfig, flags: RunFlags, params, x, positions,
 
 def forward(cfg: ArchConfig, params, batch: dict,
             flags: RunFlags = DEFAULT_FLAGS):
-    """Training forward: returns (logits (B,S,V) fp32, aux_loss)."""
+    """Training forward: returns (logits (B,S,V) fp32, stats) with stats
+    ``{"aux_loss"}``, and for MoE ``moe_assigned`` (assignments to held
+    experts, summed over layers) and ``moe_max_load`` (the most rows one
+    held expert got in any layer)."""
     x = embed_input(cfg, params, batch, flags)
     s = x.shape[1]
     positions = jnp.arange(s)
     if cfg.block_type == BLOCK_ATTN:
+        if "dense_layers" in params:
+            x, dense, _ = attn_stack(cfg, flags, params["dense_layers"], x,
+                                     positions)
         x, aux, _ = attn_stack(cfg, flags, params["layers"], x, positions)
+        if "dense_layers" in params:
+            aux = dict(aux, aux_loss=aux["aux_loss"] + dense["aux_loss"])
     else:
         x, aux, _, _ = ssm_stack(cfg, flags, params, x, positions)
+        aux = {"aux_loss": aux}
     return lm_logits(cfg, params, x), aux
 
 
 def init_cache(cfg: ArchConfig, batch_size: int, max_len: int,
                flags: RunFlags = DEFAULT_FLAGS):
     """Zero-initialized serving cache (shape donor for decode dry-runs)."""
+    _check_servable(cfg)
     hd = cfg.resolved_head_dim
     cache: dict = {"pos": jnp.zeros((), jnp.int32)}
     if cfg.block_type == BLOCK_ATTN:
@@ -466,6 +525,7 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_len: int,
 def prefill(cfg: ArchConfig, params, batch: dict, max_len: int,
             flags: RunFlags = DEFAULT_FLAGS):
     """Prefill: run the prompt, return (last-token logits (B,1,V), cache)."""
+    _check_servable(cfg)
     x = embed_input(cfg, params, batch, flags)
     b, s = x.shape[0], x.shape[1]
     positions = jnp.arange(s)
@@ -498,6 +558,7 @@ def decode_step(cfg: ArchConfig, params, cache: dict, tokens: jax.Array,
     """One decode step: tokens (B, 1) at position cache['pos'].
 
     Returns (logits (B,1,V), new_cache)."""
+    _check_servable(cfg)
     pos = cache["pos"]
     x = jnp.take(params["embed"], tokens, axis=0).astype(flags.compute_dtype)
     positions = jnp.full((x.shape[0], 1), pos, jnp.int32)

@@ -47,7 +47,11 @@ from repro.serve.sampling import SampleConfig, sample_tokens
 def validate_paged_support(cfg: ArchConfig) -> int:
     """Paged serving supports uniform-window attention stacks; returns the
     (single) window size.  Grouped local:global (gemma3), SSM and frontend
-    archs keep the dense legacy loop."""
+    archs keep the dense legacy loop; latent attention (MLA) has no cache
+    here at all."""
+    why = TF.serving_refusal(cfg)
+    if why:
+        raise NotImplementedError(f"paged serving: {why}")
     if cfg.block_type != BLOCK_ATTN:
         raise NotImplementedError(
             f"paged serving needs an attention stack, got {cfg.block_type}")
@@ -147,6 +151,7 @@ def make_paged_decode_logits(cfg: ArchConfig, pcfg: PagedCacheConfig,
             y2 = L.rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
             if cfg.is_moe:
                 out2, a = MOE.moe_block(lp["moe"], cfg, y2)
+                a = a["aux_loss"]
             else:
                 out2, a = L.mlp_block(lp["mlp"], y2), 0.0
             x = TF._constrain(x + out2, flags)

@@ -35,7 +35,7 @@ def test_forward_shapes_and_finite(arch):
         lambda p, b: TF.forward(cfg, p, b, FLAGS))(params, _batch(cfg))
     assert logits.shape == (B, S, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
-    assert np.isfinite(float(aux))
+    assert np.isfinite(float(aux["aux_loss"]))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -66,7 +66,7 @@ def test_exact_config_matches_assignment(arch):
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
         "internvl2-2b": (24, 2048, 16, 8, 8192, 92553),
         "grok-1-314b": (64, 6144, 48, 8, 32768, 131072),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonshot-v1-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
         "rwkv6-1.6b": (24, 2048, 0, 0, 7168, 65536),
         "mistral-nemo-12b": (40, 5120, 32, 8, 14336, 131072),
@@ -81,6 +81,11 @@ def test_exact_config_matches_assignment(arch):
         assert (cfg.n_experts, cfg.experts_per_token) == (8, 2)
     if arch == "moonshot-v1-16b-a3b":
         assert (cfg.n_experts, cfg.experts_per_token) == (64, 6)
+        assert (cfg.moe_d_ff, cfg.n_shared_experts, cfg.first_k_dense) == \
+            (1408, 2, 1)
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert (cfg.router_score, cfg.router_scale) == ("sigmoid", 2.446)
     if arch == "mixtral-8x7b":
         assert (cfg.n_experts, cfg.experts_per_token) == (8, 2)
         assert cfg.sliding_window == 4096
@@ -97,6 +102,7 @@ def test_param_counts_plausible():
     assert 2.5e11 < get_config("grok-1-314b").param_count() < 4e11
     assert 3.5e10 < get_config("mixtral-8x7b").param_count() < 5.5e10
     assert 1.2e9 < get_config("qwen3-1.7b").param_count() < 2.5e9
-    # moonshot activates ~3B of ~16B
+    # Moonlight-16B-A3B: 16B in all, 3B active a token, as published
     ms = get_config("moonshot-v1-16b-a3b")
-    assert ms.active_param_count() < 0.45 * ms.param_count()
+    assert 15e9 < ms.param_count() < 17e9
+    assert 2.5e9 < ms.active_param_count() < 3.5e9

@@ -141,8 +141,7 @@ def test_make_act_rules_kinds(mesh):
     from repro.configs import get_config
     cfg = get_config("mixtral-8x7b").reduced()
     rules = SH.make_act_rules(cfg, mesh, batch_size=8, seq_len=64)
-    for kind in ("residual", "ffn_hidden", "attn_q", "attn_kv", "logits",
-                 "moe_expert", "moe_hidden"):
+    for kind in ("residual", "ffn_hidden", "attn_q", "attn_kv", "logits"):
         assert kind in rules and isinstance(rules[kind], NamedSharding), kind
     # inside shard_map the data axes must be dropped
     inner = SH.make_act_rules(cfg, mesh, batch_size=8, seq_len=64,

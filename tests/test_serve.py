@@ -367,6 +367,21 @@ def test_validate_paged_support():
         validate_paged_support(gemma)       # non-uniform local:global mix
 
 
+def test_serving_refuses_latent_attention():
+    """MLA (Moonlight) has no latent cache: paged serving and the dense
+    loop's prefill / decode refuse it, naming it."""
+    cfg = get_config("moonshot-v1-16b-a3b").reduced()
+    with pytest.raises(NotImplementedError, match="MLA"):
+        validate_paged_support(cfg)
+    params = init_params(TF.model_defs(cfg), jax.random.PRNGKey(0))
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(NotImplementedError, match="moonshot-v1-16b-a3b"):
+        TF.prefill(cfg, params, {"tokens": tokens}, 16)
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        TF.decode_step(cfg, params, {"pos": jnp.zeros((), jnp.int32)},
+                       tokens[:, :1])
+
+
 def _run_engine(engine, prompts, gens):
     sched = ContinuousScheduler(engine)
     trace = [Request(rid=i, prompt=p, max_new=g, arrival=0)

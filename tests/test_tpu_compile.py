@@ -28,6 +28,7 @@ from repro.kernels.swa_attention import ops as SWA
 from repro.kernels.swa_attention.kernel import swa_decode_attention
 from repro.kernels.topk_ef import ops as TK
 from repro.kernels.topk_ef.kernel import topk_ef
+from repro.models import moe as MOE
 
 
 @pytest.fixture(scope="module")
@@ -72,7 +73,32 @@ TOPK_R, TOPK_K = 32768, 2048               # ratio 1/16
 ONEBIT_R = 43008
 CR_S, CR_R = 4, 32640                      # four workers' sign panels
 SIM_P, SIM_D = 32, 512
+# the Moonlight cell's grouped expert products: 8,192 tokens x 6 choices
+# of sorted rows over the 8 held experts of 2,048 x 1,408
+GMM_M, GMM_D, GMM_F, GMM_E = 8192 * 6, 2048, 1408, 8
+
+
+def _gmm_grad(x, w, sizes):
+    return jax.grad(lambda x, w: jnp.sum(MOE.grouped_matmul(
+        x, w, sizes).astype(jnp.float32) ** 2), argnums=(0, 1))(x, w)
+
+
+def _gmm_shapes(k, n):
+    return lambda s: (_sds(s, (GMM_M, k), jnp.bfloat16),
+                      _sds(s, (GMM_E, k, n), jnp.bfloat16),
+                      _sds(s, (GMM_E,), jnp.int32))
+
+
 KERNELS = {
+    "moe.gmm.gate_up": (
+        MOE.grouped_matmul, _gmm_shapes(GMM_D, GMM_F),
+        lambda: MOE.gmm_why_not(GMM_M, GMM_D, GMM_F)),
+    "moe.gmm.down": (
+        MOE.grouped_matmul, _gmm_shapes(GMM_F, GMM_D),
+        lambda: MOE.gmm_why_not(GMM_M, GMM_F, GMM_D)),
+    "moe.gmm.backward": (
+        _gmm_grad, _gmm_shapes(GMM_D, GMM_F),
+        lambda: MOE.gmm_why_not(GMM_M, GMM_D, GMM_F)),
     "topk_ef": (
         lambda g, e: topk_ef(g, e, k=TOPK_K),
         lambda s: (_sds(s, (16, TOPK_R)), _sds(s, (16, TOPK_R))),
