@@ -165,7 +165,7 @@ def ef_compress_rows(comp: Compressor, updates: jax.Array, errs: jax.Array,
     if comp.kind == "topk":
         from repro.kernels.topk_ef.ops import compress_leaf
         _, _, new_errs = compress_leaf(updates.astype(jnp.float32), errs,
-                                       comp.ratio, impl=impl)
+                                       comp.ratio, impl=impl, payload=False)
         return w - new_errs, new_errs
     if comp.kind == "onebit" and w.shape[1] % 8 == 0:
         from repro.kernels.onebit_ef.ops import compress_leaf

@@ -8,19 +8,23 @@ the kernel does not take the call, the selection is exact either way and
 picked by the row length alone: ``lax.top_k`` (a sort) for short rows,
 the threshold search of `select.py` from :data:`THRESHOLD_MIN_R` on.  The
 pick is counted at the ``topk_ef.select`` site as ``sort`` or
-``threshold``."""
+``threshold``.  The threshold search's compaction resolves at the
+``topk_ef.compact`` site: the streaming kernel of `compact.py`, or the jnp
+compaction as its reference."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.dispatch import choose, record, vmem_why_not
+from repro.kernels.topk_ef import compact
 from repro.kernels.topk_ef.kernel import topk_ef, vmem_bytes
 from repro.kernels.topk_ef.ref import topk_ef_ref
 from repro.kernels.topk_ef.select import topk_threshold_ef
 
 SITE = "topk_ef"
 SELECT_SITE = "topk_ef.select"
+COMPACT_SITE = "topk_ef.compact"
 BLOCK_ROWS = 8
 #: Row length from which the threshold search is faster than the sort of
 #: ``lax.top_k`` on a v5e chip (k = R / 16; PERF.md section 6).
@@ -35,20 +39,35 @@ def why_not(m: int, r: int, k: int) -> str | None:
                         f"({BLOCK_ROWS}, {r}) row block")
 
 
+def compact_why_not(r: int) -> str | None:
+    """None when the compaction kernel takes a row of R entries."""
+    block = compact.BLOCK_LINES * compact.LANES
+    if -(-r // block) * block >= 2 ** 31:
+        return f"R={r}: positions of the last block overflow int32"
+    return vmem_why_not(compact.vmem_bytes(), "compaction block and ring")
+
+
 def compress_leaf(g2d: jax.Array, err2d: jax.Array, ratio: float, *,
-                  impl: str = "auto"):
-    """(M, R) leaf -> (vals, idx, new_err), k = max(1, round(R * ratio))."""
+                  impl: str = "auto", payload: bool = True):
+    """(M, R) leaf -> (vals, idx, new_err), k = max(1, round(R * ratio)).
+    ``payload=False``: the caller keeps only ``new_err``, so a long row
+    keeps the jnp compaction, whose gathers XLA drops as dead code."""
     m, r = g2d.shape
     k = max(1, int(round(r * ratio)))
     use_kernel, interpret = choose(SITE, impl, why_not(m, r, k))
     if use_kernel:
         return topk_ef(g2d, err2d, k=k, block_rows=BLOCK_ROWS,
                        interpret=interpret)
-    if r >= THRESHOLD_MIN_R:
-        record(SELECT_SITE, "threshold")
-        return topk_threshold_ef(g2d, err2d, k=k)
-    record(SELECT_SITE, "sort")
-    return topk_ef_ref(g2d, err2d, k=k)
+    if r < THRESHOLD_MIN_R:
+        record(SELECT_SITE, "sort")
+        return topk_ef_ref(g2d, err2d, k=k)
+    record(SELECT_SITE, "threshold")
+    use_kernel = interpret = False
+    if payload:
+        use_kernel, interpret = choose(COMPACT_SITE, impl,
+                                       compact_why_not(r))
+    return topk_threshold_ef(g2d, err2d, k=k, kernel=use_kernel,
+                             interpret=interpret)
 
 
 def decompress_sum(vals: jax.Array, idx: jax.Array, r: int) -> jax.Array:

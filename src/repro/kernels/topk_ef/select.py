@@ -22,13 +22,17 @@ about a fifth of the row.  Rows are worked one at a time as flat arrays:
 a (1, N) array would fill one sublane of eight in the TPU's vector
 registers.  The payload comes out in ascending index order, not magnitude
 order; its consumers scatter distinct indices, so their dense result is
-the same.
+the same.  Where the caller asks for it, the compaction is instead
+:func:`repro.kernels.topk_ef.compact.ef_compact`, one streaming Pallas
+pass with no random access; the jnp one is its reference.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.topk_ef.compact import ef_compact
 
 #: One above the bit pattern of +inf: every finite or infinite magnitude
 #: lies below it, so ``count(bits >= _HI) < k`` holds from the start.
@@ -97,7 +101,7 @@ def _exclusive_cumsum(x: jax.Array) -> jax.Array:
     return jnp.cumsum(x, dtype=jnp.int32) - x
 
 
-def _select_row(w: jax.Array, k: int):
+def _select_row(w: jax.Array, k: int, kernel: bool, interpret: bool):
     """(R,) f32 -> (vals (k,), idx (k,) ascending, new_err (R,))."""
     r = w.shape[0]
     n_lines = -(-r // LANES)
@@ -118,8 +122,13 @@ def _select_row(w: jax.Array, k: int):
         at_cut = WORD * word + select_bit(ties, need - before - 1)
         last_tie = jnp.max(jnp.where(cut, at_cut, -1))
         keep = above | (ties & _upto(last_tie - WORD * word))
-        # each nonempty word marks the slot where its entries start
         n_keep = pop(keep).astype(jnp.int32)
+        if kernel:
+            counts = n_keep.reshape(n_lines, LANES // WORD).sum(1)
+            vals, idx, new_err = ef_compact(lines, counts, t, last_tie, k=k,
+                                            interpret=interpret)
+            return vals, idx, new_err.reshape(-1)[:r]
+        # each nonempty word marks the slot where its entries start
         marks = jnp.full((k,), -1, jnp.int32).at[
             _exclusive_cumsum(n_keep)].max(
                 jnp.where(n_keep > 0, word, -1), mode="drop",
@@ -137,13 +146,18 @@ def _select_row(w: jax.Array, k: int):
     return vals, idx, new_err
 
 
-def topk_threshold_ef(g: jax.Array, err: jax.Array, *, k: int):
+def topk_threshold_ef(g: jax.Array, err: jax.Array, *, k: int,
+                      kernel: bool = False, interpret: bool = False):
     """g, err (M, R) -> (vals (M, k) f32, idx (M, k) i32, new_err (M, R)
-    f32): :func:`topk_ef_ref`'s contract, with ``idx`` ascending."""
+    f32): :func:`topk_ef_ref`'s contract, with ``idx`` ascending.  The
+    compaction is :func:`ef_compact`'s kernel where ``kernel`` is set
+    (``interpret`` runs it in the interpreter), else the jnp one."""
     w = err + g.astype(jnp.float32)
     r = w.shape[1]
     if -(-r // LANES) * LANES >= 2 ** 31:
         raise ValueError(f"rows of {r} entries: positions overflow int32")
     if w.shape[0] == 1:     # the loop below would keep a (1, R) layout
-        return tuple(x[None] for x in _select_row(w.reshape(r), k))
-    return jax.lax.map(lambda row: _select_row(row, k), w)
+        return tuple(x[None] for x in _select_row(w.reshape(r), k,
+                                                  kernel, interpret))
+    return jax.lax.map(
+        lambda row: _select_row(row, k, kernel, interpret), w)

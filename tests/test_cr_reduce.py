@@ -263,12 +263,17 @@ if HAVE_HYPOTHESIS:
 # selection by row length: threshold search for long rows, sort below
 # ---------------------------------------------------------------------------
 
-def test_compress_leaf_selects_by_row_length():
+@pytest.mark.parametrize("compact_impl", ["auto", "kernel"])
+def test_compress_leaf_selects_by_row_length(compact_impl, monkeypatch):
     """compress_leaf counts its pick at ``topk_ef.select`` by the row
     length alone — the threshold search from THRESHOLD_MIN_R on, the sort
     of lax.top_k below — and the async engine's compact path, whose long
     leaves take the threshold search, walks the trajectory of the
-    densified path, which keeps lax.top_k."""
+    densified path, which keeps lax.top_k.  The search's compaction
+    resolves at ``topk_ef.compact``: the jnp reference under ``auto`` on
+    the CPU, the streaming kernel (interpreted) when asked.  It is asked for
+    at that site alone: the engine's M = 1 deposits and short rows refuse
+    ``kernel_impl="kernel"``."""
     import dataclasses
 
     from repro.configs import get_config
@@ -289,6 +294,16 @@ def test_compress_leaf_selects_by_row_length():
         row = jax.ShapeDtypeStruct((1, r), jnp.float32)
         jax.eval_shape(lambda a: TK.compress_leaf(a, a, 1 / 16), row)
         assert dispatch.resolutions()[TK.SELECT_SITE] == {how: 1}
+    if compact_impl == "kernel":    # ask for the kernel at that site alone
+        choose = TK.choose
+        monkeypatch.setattr(TK, "choose", lambda site, impl, why=None: choose(
+            site, "kernel" if site == TK.COMPACT_SITE else impl, why))
+    dispatch.reset_resolutions()
+    row = jax.ShapeDtypeStruct((1, TK.THRESHOLD_MIN_R), jnp.float32)
+    jax.eval_shape(lambda a: TK.compress_leaf(a, a, 1 / 16), row)
+    compacted = ("kernel (interpret)" if compact_impl == "kernel"
+                 else "ref (backend cpu)")
+    assert dispatch.resolutions()[TK.COMPACT_SITE] == {compacted: 1}
 
     base = get_config("qwen3-1.7b").reduced()
     cfg = dataclasses.replace(
@@ -316,10 +331,12 @@ def test_compress_leaf_selects_by_row_length():
             p, opt_state, state, m = step(p, opt_state, state, b)
             traj.append((float(m["loss"]),
                          [np.asarray(x) for x in jax.tree.leaves(p)]))
-        return traj, dispatch.resolutions().get(TK.SELECT_SITE, {})
+        return traj, dispatch.resolutions()
 
     fused, picks = run(True)
-    assert picks.get("threshold", 0) > 0 and picks.get("sort", 0) > 0
+    select = picks.get(TK.SELECT_SITE, {})
+    assert select.get("threshold", 0) > 0 and select.get("sort", 0) > 0
+    assert picks[TK.COMPACT_SITE] == {compacted: select["threshold"]}
     legacy, _ = run(False)
     for t, ((lf, pf), (ll, pl)) in enumerate(zip(fused, legacy)):
         assert lf == ll, f"loss diverged at step {t}"

@@ -5,11 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import dispatch
 from repro.kernels.onebit_ef import onebit_ef, onebit_ef_ref, unpack
 from repro.kernels.swa_attention import swa_decode_attention, swa_decode_ref
 from repro.kernels.topk_ef import topk_ef, topk_ef_ref
-from repro.kernels.topk_ef.ops import THRESHOLD_MIN_R, decompress_sum
-from repro.kernels.topk_ef import select
+from repro.kernels.topk_ef.ops import (COMPACT_SITE, THRESHOLD_MIN_R,
+                                      decompress_sum)
+from repro.kernels.topk_ef import compact, select
 
 
 @pytest.mark.parametrize("m,r,k", [(8, 64, 4), (16, 256, 8), (32, 128, 1),
@@ -38,8 +40,17 @@ def _select_rows(kind, m, r, seed):
         w = np.zeros((m, r), np.float32)
         w[:, r // 3] = -0.0
         return w
+    if kind == "clump":         # the largest r/16 in one run mid-row
+        w = (rng.standard_normal((m, r)) * 1e-3).astype(np.float32)
+        lo = r // 2 - r // 32
+        w[:, lo:lo + r // 16] += np.float32(10.0)
+        return w
     # denormals: magnitudes below the smallest normal float32
     return (rng.standard_normal((m, r)) * 1e-39).astype(np.float32)
+
+
+#: Lines of the compaction kernel's blocks, in entries.
+_BLOCK = compact.BLOCK_LINES * compact.LANES
 
 
 @pytest.mark.parametrize("m,r,k", [
@@ -47,28 +58,45 @@ def _select_rows(kind, m, r, seed):
     (8, THRESHOLD_MIN_R + 5, (THRESHOLD_MIN_R + 5) // 16),  # above
     (8, 96, 1),
     (1, 200, 200),
+    # "clump" keeps block 1 alone, "zeros" block 0 alone
+    (1, 3 * _BLOCK + 1000, 4000),
+    # "int_ties": ties at the threshold taken past the first block; the
+    # clump's run straddles the first block boundary
+    (1, 3 * _BLOCK + 1000, 25000),
+    # every entry kept, over more than one half of the output ring
+    (1, 2 * _BLOCK + 300, 2 * _BLOCK + 300),
 ])
-@pytest.mark.parametrize("kind", ["gauss", "int_ties", "zeros", "denormal"])
+@pytest.mark.parametrize("kind", ["gauss", "int_ties", "zeros", "denormal",
+                                  "clump"])
 @pytest.mark.parametrize("residual", [False, True])
-def test_threshold_select_matches_ref(kind, m, r, k, residual):
+@pytest.mark.parametrize("impl", ["ref", "kernel"])
+def test_threshold_select_matches_ref(kind, m, r, k, residual, impl):
     """The threshold search picks lax.top_k's set, ties to the lowest
     index, and leaves a bit-identical residual; idx come ascending.  The
     row is split between ``g`` and an EF residual ``err`` or all in
-    ``g``."""
+    ``g``.  The compaction is the jnp one (``ref``) or the streaming
+    kernel, interpreted (``kernel``), which must also match the jnp one
+    bit for bit."""
     w = _select_rows(kind, m, r, m * r + k)
     half = np.float32(0.5) * w if residual else np.zeros_like(w)
     g, e = jnp.asarray(w - half), jnp.asarray(half)
-    v1, i1, e1 = jax.jit(select.topk_threshold_ef,
-                         static_argnames="k")(g, e, k=k)
+    kernel, interpret = dispatch.choose(COMPACT_SITE, impl)
+    got = jax.jit(select.topk_threshold_ef,
+                  static_argnames=("k", "kernel", "interpret"))(
+                      g, e, k=k, kernel=kernel, interpret=interpret)
     v2, i2, e2 = jax.jit(topk_ef_ref, static_argnames="k")(g, e, k=k)
     order = np.argsort(np.asarray(i2), axis=1)
-    np.testing.assert_array_equal(
-        np.asarray(i1), np.take_along_axis(np.asarray(i2), order, 1))
-    np.testing.assert_array_equal(
-        np.asarray(v1).view(np.int32),
-        np.take_along_axis(np.asarray(v2), order, 1).view(np.int32))
-    np.testing.assert_array_equal(np.asarray(e1).view(np.int32),
-                                  np.asarray(e2).view(np.int32))
+    want = (np.take_along_axis(np.asarray(v2), order, 1),
+            np.take_along_axis(np.asarray(i2), order, 1), np.asarray(e2))
+    if kernel:
+        jnp_path = jax.jit(select.topk_threshold_ef,
+                           static_argnames="k")(g, e, k=k)
+        for a, b in zip(got, jnp_path):
+            np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                          np.asarray(b).view(np.int32))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                      np.asarray(b).view(np.int32))
 
 
 @pytest.mark.parametrize("m,r", [(8, 64), (16, 256), (8, 1024), (24, 96)])

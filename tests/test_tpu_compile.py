@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import compression as COMP
 from repro.kernels import dispatch
 from repro.kernels.cr_reduce import kernel as CRK
 from repro.kernels.cr_reduce import ops as CR
@@ -26,6 +27,7 @@ from repro.kernels.sim_step import kernel as SSK
 from repro.kernels.sim_step import ops as SSO
 from repro.kernels.swa_attention import ops as SWA
 from repro.kernels.swa_attention.kernel import swa_decode_attention
+from repro.kernels.topk_ef import compact as TKC
 from repro.kernels.topk_ef import ops as TK
 from repro.kernels.topk_ef.kernel import topk_ef
 from repro.models import moe as MOE
@@ -70,6 +72,8 @@ def _sds(sharding, shape, dtype=jnp.float32):
 # (8 kv heads of 128, 2 query heads per kv head) over 8 request slots; the
 # simulator's are the largest (p, d) of its benchmark grids.
 TOPK_R, TOPK_K = 32768, 2048               # ratio 1/16
+# the compaction kernel takes any row length: the tied embedding as one row
+EMBED_R = 151936 * 2048
 ONEBIT_R = 43008
 CR_S, CR_R = 4, 32640                      # four workers' sign panels
 SIM_P, SIM_D = 32, 512
@@ -103,6 +107,13 @@ KERNELS = {
         lambda g, e: topk_ef(g, e, k=TOPK_K),
         lambda s: (_sds(s, (16, TOPK_R)), _sds(s, (16, TOPK_R))),
         lambda: TK.why_not(16, TOPK_R, TOPK_K)),
+    "topk_ef.compact": (
+        lambda lines, counts, t, last: TKC.ef_compact(
+            lines, counts, t, last, k=EMBED_R // 16),
+        lambda s: (_sds(s, (EMBED_R // 128, 128)),
+                   _sds(s, (EMBED_R // 128,), jnp.int32),
+                   _sds(s, (), jnp.int32), _sds(s, (), jnp.int32)),
+        lambda: TK.compact_why_not(EMBED_R)),
     "onebit_ef": (
         onebit_ef,
         lambda s: (_sds(s, (16, ONEBIT_R)), _sds(s, (16, ONEBIT_R))),
@@ -171,11 +182,12 @@ def test_routed_kernels_raise_on_tpu(site, on_tpu):
         jax.eval_shape(ROUTED[site])
 
 
-@pytest.mark.parametrize("m,r", [(1, 151936 * 2048), (8, 1 << 20)])
+@pytest.mark.parametrize("m,r", [(1, EMBED_R), (8, 1 << 20)])
 def test_topk_ef_whole_leaf_raises(m, r, on_tpu):
     """The tied embedding as one row (M = 1) and a row block over the VMEM
     budget are refused under ``impl="kernel"``, resolved to the reference
-    under ``"auto"``."""
+    under ``"auto"``: the threshold search, whose compaction is the
+    streaming kernel."""
     g = jax.ShapeDtypeStruct((m, r), jnp.float32)
     with pytest.raises(dispatch.KernelUnsupported):
         jax.eval_shape(lambda a, b: TK.compress_leaf(a, b, 1 / 16,
@@ -184,6 +196,20 @@ def test_topk_ef_whole_leaf_raises(m, r, on_tpu):
     jax.eval_shape(lambda a, b: TK.compress_leaf(a, b, 1 / 16), g, g)
     (how,) = dispatch.resolutions()[TK.SITE]
     assert how.startswith("ref (")
+    assert dispatch.resolutions()[TK.COMPACT_SITE] == {"kernel": 1}
+
+
+def test_ef_residual_skips_the_compaction(one_chip, on_tpu):
+    """The EF rounds that keep only the residual (``compression``'s
+    batched rows) compile no compaction kernel for a long row: the jnp
+    compaction they keep is dead code to XLA."""
+    comp = COMP.topk_compressor(1 / 16)
+    rows = _sds(one_chip, (4, 1 << 20))
+    dispatch.reset_resolutions()
+    compiled = jax.jit(lambda u, e: COMP.ef_compress_rows(comp, u, e)).lower(
+        rows, rows).compile()
+    assert TK.COMPACT_SITE not in dispatch.resolutions()
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_auto_picks_compiled_kernel_on_tpu(on_tpu):
